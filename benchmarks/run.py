@@ -70,6 +70,9 @@ def _ledger_path(argv: list[str]) -> tuple[str | None, list[str]]:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     from benchmarks import (
         check_report,
         obs_report,

@@ -17,10 +17,12 @@ GFLOP/s, and an allclose check against the single-device reference.  On an
 memcpys, so "overlapped >= gather" is a sanity floor; on a real TPU mesh the
 gap is the hidden ICI time.
 
-The measurement needs the forced-device-count flag set before the first jax
-call, so ``run()`` (the ``benchmarks.run`` entry) re-executes this module in
-a subprocess with the flag injected; invoking the module directly inherits
-whatever devices the environment already has::
+On a TPU, ``run()`` (the ``benchmarks.run`` entry) measures in its own
+process over the attached chips: the parent already holds them, so a child
+could not get them.  Off TPU it re-executes this module in a subprocess
+with the forced-device-count flag, which must precede the first jax call;
+invoking the module directly inherits whatever devices the environment
+already has::
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     PYTHONPATH=src python -m benchmarks.tp_matmul
@@ -39,7 +41,16 @@ DEFAULT_TP = 8
 
 
 def run(tp: int = DEFAULT_TP) -> list[str]:
-    """benchmarks.run entry: subprocess with the forced-device-count flag."""
+    """benchmarks.run entry: in process over the TPU's own devices, else a
+    subprocess with the forced-device-count flag."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        tp = min(tp, len(jax.devices()))
+        lines = _bench(_parser().parse_args(["--tp", str(tp)]))
+        if any(ln.startswith("FAIL") for ln in lines):
+            raise RuntimeError("tp_matmul failed:\n" + "\n".join(lines))
+        return lines
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={tp}"
     env["PYTHONPATH"] = (
@@ -70,7 +81,7 @@ def _time_best(fn, *args, repeats: int = 5) -> tuple[float, float]:
     return min(times), sum(times) / len(times)
 
 
-def _main(argv=None) -> int:
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tp", type=int, default=DEFAULT_TP)
     ap.add_argument("--m", type=int, default=2048)
@@ -78,14 +89,18 @@ def _main(argv=None) -> int:
     ap.add_argument("--k", type=int, default=512)
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--repeats", type=int, default=5)
-    args = ap.parse_args(argv)
+    return ap
 
+
+def _bench(args) -> list[str]:
+    """BENCH lines for each mode, then a FAIL or WARN line if one applies."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from repro.distributed import collective_matmul as cm
     from repro.kernels.systolic import ops as systolic_ops
+    from repro.launch.mesh import make_mesh
 
     n_dev = len(jax.devices())
     if n_dev < args.tp:
@@ -93,7 +108,7 @@ def _main(argv=None) -> int:
             f"need {args.tp} devices, have {n_dev}; set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={args.tp}"
         )
-    mesh = jax.make_mesh((args.tp,), ("model",))
+    mesh = make_mesh((args.tp,), ("model",))
     dtype = jnp.dtype(args.dtype)
     a = jax.random.normal(jax.random.PRNGKey(0), (args.m, args.k)).astype(dtype)
     b = jax.random.normal(jax.random.PRNGKey(1), (args.k, args.n)).astype(dtype)
@@ -138,19 +153,23 @@ def _main(argv=None) -> int:
                 }
             )
         )
-    for ln in lines:
-        print(ln)
     rows = {json.loads(ln[len("BENCH "):])["mode"]: json.loads(ln[len("BENCH "):])
             for ln in lines}
     if not all(r["allclose_vs_single"] for r in rows.values()):
-        print("FAIL: sharded result diverged from the single-device reference")
-        return 1
-    if rows["overlapped"]["best_ms"] > rows["gather"]["best_ms"] * 1.1:
+        lines.append("FAIL: sharded result diverged from the single-device reference")
+    elif rows["overlapped"]["best_ms"] > rows["gather"]["best_ms"] * 1.1:
         # >10% slower than the unoverlapped baseline means the overlap
         # machinery itself is costing time -- that is a regression signal,
         # not noise.
-        print("WARN: overlapped slower than gather-then-matmul baseline")
-    return 0
+        lines.append("WARN: overlapped slower than gather-then-matmul baseline")
+    return lines
+
+
+def _main(argv=None) -> int:
+    lines = _bench(_parser().parse_args(argv))
+    for ln in lines:
+        print(ln)
+    return 1 if any(ln.startswith("FAIL") for ln in lines) else 0
 
 
 if __name__ == "__main__":

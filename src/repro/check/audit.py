@@ -35,6 +35,7 @@ from typing import Any, Callable, Iterable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from repro.check.findings import AUDIT, Finding
 from repro.core import dse, hw
@@ -142,6 +143,14 @@ def _is_streamed(block_mapping, grid_rank: int) -> bool:
         return True  # unknown index map: assume streamed (conservative)
 
 
+def _block_dim(d) -> int:
+    """One block-shape entry as an int: Pallas records ``Blocked(n)`` (and
+    other sized entries) for a window dim and ``Squeezed`` for a dim the
+    kernel never sees, which spans one element."""
+    size = getattr(d, "block_size", d)
+    return 1 if size is None or isinstance(size, pl.Squeezed) else int(size)
+
+
 def _eqn_to_kernel(eqn) -> TracedKernel:
     gm = eqn.params["grid_mapping"]
     grid = tuple(int(g) for g in gm.grid)
@@ -152,10 +161,7 @@ def _eqn_to_kernel(eqn) -> TracedKernel:
     in_avals = [getattr(v, "aval", None) for v in eqn.invars][-n_in:] if n_in else []
     for pos, bm in enumerate(mappings):
         is_output = pos >= n_in
-        shape = tuple(
-            1 if d is None else int(d)
-            for d in bm.block_shape
-        )
+        shape = tuple(_block_dim(d) for d in bm.block_shape)
         dtype = bm.block_aval.dtype
         aval = None if is_output else in_avals[pos]
         windows.append(
@@ -183,9 +189,8 @@ def _eqn_to_kernel(eqn) -> TracedKernel:
                     aval.dtype
                 ).itemsize
     cost = eqn.params.get("cost_estimate")
-    name_info = eqn.params.get("name_and_src_info")
     return TracedKernel(
-        name=getattr(name_info, "name", "pallas_call"),
+        name=eqn.params.get("name") or "pallas_call",
         grid=grid,
         windows=tuple(windows),
         scratch_bytes=scratch_bytes,

@@ -115,6 +115,23 @@ class BlockPlan:
     def fits_vmem(self, chip: hw.Chip | str | None = None) -> bool:
         return self.vmem_bytes() <= hw.get_chip(chip).vmem_budget_bytes
 
+    def vmem_limit_bytes(self, chip: hw.Chip | str | None = None) -> int:
+        """Scoped-VMEM limit the kernel running this plan is compiled with.
+
+        Without one, Mosaic enforces its own default (16 MiB on v5e), far
+        below the fitter's budget, and refuses every plan in between.  Mosaic
+        also allocates beyond ``vmem_bytes()``: a second output window and
+        the (bm, bn) fp32 result of each block dot.  Compiled against a
+        described v5e, the smallest limit that compiled was at most 1.46x
+        the working set over bf16 and fp32 plans from 256x256x512 to
+        1024x1024x2048, so twice the working set covers it.  The floor keeps
+        small plans at the compiler default; the cap is the physical VMEM.
+        """
+        chip = hw.get_chip(chip)
+        return min(
+            chip.vmem_capacity_bytes, max(2 * self.vmem_bytes(), _MIN_VMEM_LIMIT)
+        )
+
     def mxu_aligned(self, chip: hw.Chip | str | None = None) -> bool:
         """All three dims hardware aligned (lane=128; sublane handled by
         Mosaic for the minor-most dim)."""
@@ -216,6 +233,10 @@ class BlockPlan:
         if self.tp == 1:
             return True
         return self.hop_seconds(chip, links) <= self.shard_step_seconds(chip)
+
+
+# Mosaic's default scoped-VMEM limit on v5e: no plan is given less.
+_MIN_VMEM_LIMIT = 16 * 1024 * 1024
 
 
 def _round_to(x: int, quantum: int) -> int:

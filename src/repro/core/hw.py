@@ -9,6 +9,7 @@ numbers are the grading constants given for this reproduction:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 
 # ---------------------------------------------------------------------------
@@ -73,6 +74,9 @@ class TPUv5e:
     # ~128 MiB VMEM per core; we leave headroom for Mosaic's own buffers
     # and for double-buffered pipelining (which doubles input block space).
     vmem_budget_bytes: int = 64 * 1024 * 1024
+    # Physical VMEM per core: the ceiling of the scoped-VMEM limit a kernel
+    # is compiled with (``BlockPlan.vmem_limit_bytes``).
+    vmem_capacity_bytes: int = 128 * 1024 * 1024
     # MXU native tile: 128x128 systolic array, 8-deep sublane packing for
     # bf16.  All matmul block dims should be multiples of these.
     mxu_dim: int = 128
@@ -120,22 +124,29 @@ TPU_V4 = TPUv5e(
     hbm_bw=1228e9,
     ici_bw_per_link=50e9,
     vmem_budget_bytes=24 * 1024 * 1024,
+    vmem_capacity_bytes=32 * 1024 * 1024,
 )
 
 
 # ---------------------------------------------------------------------------
 # Chip registry: replaces the hardcoded TPU_V5E sprinkled through the kernel
-# wrappers.  ``get_chip(None)`` returns the process-wide default, which the
-# autotuner and tests can retarget without threading a chip argument through
-# every call site.
+# wrappers.  ``get_chip(None)`` returns the process-wide default: the
+# attached TPU on a TPU backend, which the autotuner and tests can retarget
+# without threading a chip argument through every call site.
 # ---------------------------------------------------------------------------
 
 _CHIPS: dict[str, Chip] = {}
-# REPRO_CHIP retargets a whole process (e.g. serve tuned tpu_v4 plans on a
-# v4 host) without code changes; unknown names fall back to tpu_v5e at
-# first get_chip(), with a one-shot warning rather than an import error.
-_DEFAULT_CHIP_NAME = os.environ.get("REPRO_CHIP", TPU_V5E.name)
-_warned_default = False
+# set_default_chip() retargets a whole process explicitly (tests, tuning for
+# another target); it wins over everything else.
+_override: str | None = None
+
+# device_kind as JAX reports it -> registry name.  An attached TPU whose kind
+# is missing here is an error, not a default: its constants are unknown, and
+# another chip's would mislabel every plan and roofline computed from them.
+DEVICE_KINDS = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v4": "tpu_v4",
+}
 
 
 def register_chip(chip: Chip) -> Chip:
@@ -152,28 +163,45 @@ def chip_names() -> tuple[str, ...]:
     return tuple(sorted(_CHIPS))
 
 
+@functools.cache
+def attached_chip_name() -> str | None:
+    """Registry name of the attached TPU (from ``device_kind``); None when
+    JAX's backend is not a TPU."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return None
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_KINDS:
+        raise KeyError(
+            f"attached TPU has device_kind {kind!r}, which has no chip entry; "
+            f"known kinds: {sorted(DEVICE_KINDS)}"
+        )
+    return DEVICE_KINDS[kind]
+
+
+def _default_name() -> str:
+    """The target of ``get_chip(None)``: an explicit ``set_default_chip``,
+    else the attached TPU, else ``REPRO_CHIP`` (the chip a CPU run models),
+    else tpu_v5e."""
+    return (
+        _override
+        or attached_chip_name()
+        or os.environ.get("REPRO_CHIP")
+        or TPU_V5E.name
+    )
+
+
 def get_chip(name: str | Chip | None = None) -> Chip:
     """Resolve a chip by registry name; ``None`` -> the current default.
 
     Accepts an already-resolved Chip and passes it through, so call sites can
     take ``chip: str | Chip | None`` without case analysis.
     """
-    if name is None:
-        chip = _CHIPS.get(_DEFAULT_CHIP_NAME)
-        if chip is None:
-            global _warned_default
-            if not _warned_default:
-                _warned_default = True
-                import warnings
-
-                warnings.warn(
-                    f"REPRO_CHIP={_DEFAULT_CHIP_NAME!r} is not a registered "
-                    f"chip {chip_names()}; falling back to {TPU_V5E.name!r}"
-                )
-            chip = TPU_V5E
-        return chip
     if isinstance(name, TPUv5e):
         return name
+    if name is None:
+        name = _default_name()
     try:
         return _CHIPS[name]
     except KeyError:
@@ -184,10 +212,10 @@ def get_chip(name: str | Chip | None = None) -> Chip:
 
 def set_default_chip(name: str | Chip) -> Chip:
     """Set the process-wide default target (registering it if needed)."""
-    global _DEFAULT_CHIP_NAME
+    global _override
     chip = name if isinstance(name, TPUv5e) else get_chip(name)
     register_chip(chip)
-    _DEFAULT_CHIP_NAME = chip.name
+    _override = chip.name
     return chip
 
 

@@ -52,7 +52,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.obs import metrics as _obs_metrics
@@ -314,12 +313,12 @@ def all_gather_matmul(
         block=block,
         interpret=interpret,
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(axis, None), P(None, axis)),
         out_specs=P(None, axis),
-        check_rep=False,  # pallas_call has no replication rule
+        check_vma=False,  # pallas_call has no replication rule
     )
     if overlap and not isinstance(a, jax.core.Tracer):
         from repro.obs import profile as _obs_profile
@@ -416,12 +415,12 @@ def reduce_scatter_matmul(
         block=block,
         interpret=interpret,
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(None, axis), P(axis, None)),
         out_specs=P(axis, None),
-        check_rep=False,  # pallas_call has no replication rule
+        check_vma=False,  # pallas_call has no replication rule
     )
     if overlap and not isinstance(a, jax.core.Tracer):
         from repro.obs import profile as _obs_profile

@@ -72,11 +72,13 @@ def pick_mesh_for(n_devices: int) -> jax.sharding.Mesh:
     """Largest ladder mesh that fits the live device count."""
     import math
 
+    from repro.launch.mesh import make_mesh
+
     for shape, axes in elastic_meshes():
         if math.prod(shape) <= n_devices:
-            return jax.make_mesh(shape, axes)
+            return make_mesh(shape, axes)
     # last resort: whatever we have as pure DP
-    return jax.make_mesh((n_devices, 1), ("data", "model"))
+    return make_mesh((n_devices, 1), ("data", "model"))
 
 
 def restart_loop(

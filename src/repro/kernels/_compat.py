@@ -1,9 +1,8 @@
-"""Pallas API compatibility across JAX versions + interpret-mode policy.
+"""Pallas compiler parameters + interpret-mode policy shared by the kernels.
 
-Newer JAX exposes ``pltpu.CompilerParams`` with a ``GridDimensionSemantics``
-enum; 0.4.x calls it ``TPUCompilerParams`` and takes plain strings.  Kernels
-declare their grid semantics as lowercase strings ("parallel"/"arbitrary")
-and go through this shim so one source tree runs on both.
+Kernels declare their grid semantics as lowercase strings
+("parallel"/"arbitrary") and their scoped-VMEM limit in bytes, and build
+their ``pltpu.CompilerParams`` through ``tpu_compiler_params``.
 
 ``auto_interpret`` is the one implementation of the kernels' interpret-mode
 default (previously copy-pasted into every ops wrapper): interpret off-TPU,
@@ -42,16 +41,13 @@ def auto_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def tpu_compiler_params(dimension_semantics: tuple[str, ...]):
-    """CompilerParams with the given per-grid-dim semantics, any JAX version."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=tuple(dimension_semantics)
-        )
-    enum = getattr(pltpu, "GridDimensionSemantics", None)
-    if enum is not None:
-        sems = tuple(getattr(enum, s.upper()) for s in dimension_semantics)
-    else:  # pragma: no cover - future JAX that takes strings again
-        sems = tuple(dimension_semantics)
-    return cls(dimension_semantics=sems)
+def tpu_compiler_params(
+    dimension_semantics: tuple[str, ...], vmem_limit_bytes: int | None = None
+):
+    """CompilerParams with the given per-grid-dim semantics and scoped-VMEM
+    limit (``None`` leaves the compiler's own default, which is far below
+    the chip's VMEM)."""
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics),
+        vmem_limit_bytes=vmem_limit_bytes,
+    )

@@ -48,6 +48,7 @@ def grouped_matmul_call(
     bk: int,
     out_dtype,
     interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """x: (E, C, K), w: (E, K, N) -> (E, C, N); blocks must divide."""
     e, c, k = x.shape
@@ -60,7 +61,9 @@ def grouped_matmul_call(
     w_spec = pl.BlockSpec((1, bk, bn), lambda ee, i, j, kk: (ee, kk, j))
     o_spec = pl.BlockSpec((1, bc, bn), lambda ee, i, j, kk: (ee, i, j))
 
-    params = tpu_compiler_params(("parallel", "parallel", "parallel", "arbitrary"))
+    params = tpu_compiler_params(
+        ("parallel", "parallel", "parallel", "arbitrary"), vmem_limit_bytes
+    )
     cost = pl.CostEstimate(
         flops=2 * e * c * k * n,
         bytes_accessed=x.size * x.dtype.itemsize * (n // bn)

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import hw
+from repro.core.blocking import BlockPlan
 from repro.core.blocking import round_up as _round_up
 from repro.kernels._compat import auto_interpret as _auto_interpret
 from repro.kernels.grouped import kernel as _kernel
@@ -24,9 +25,10 @@ def _tuned_block(c: int, n: int, k: int, dtype, chip) -> tuple[int, int, int] | 
 
 
 @functools.partial(
-    jax.jit, static_argnames=("out_dtype", "bc", "bn", "bk", "interpret")
+    jax.jit,
+    static_argnames=("out_dtype", "bc", "bn", "bk", "interpret", "vmem_limit"),
 )
-def _grouped_jit(x, w, *, out_dtype, bc, bn, bk, interpret):
+def _grouped_jit(x, w, *, out_dtype, bc, bn, bk, interpret, vmem_limit):
     e, c, k = x.shape
     n = w.shape[2]
     cp, np_, kp = _round_up(c, bc), _round_up(n, bn), _round_up(k, bk)
@@ -35,7 +37,8 @@ def _grouped_jit(x, w, *, out_dtype, bc, bn, bk, interpret):
     if (kp, np_) != (k, n):
         w = jnp.pad(w, ((0, 0), (0, kp - k), (0, np_ - n)))
     y = _kernel.grouped_matmul_call(
-        x, w, bc=bc, bn=bn, bk=bk, out_dtype=out_dtype, interpret=interpret
+        x, w, bc=bc, bn=bn, bk=bk, out_dtype=out_dtype, interpret=interpret,
+        vmem_limit_bytes=vmem_limit,
     )
     return y[:, :c, :n]
 
@@ -80,6 +83,12 @@ def grouped_matmul(
     bn = bn or min(512, _round_up(n, chip.lane_dim))
     bk = bk or min(1024, _round_up(k, chip.lane_dim))
     interpret = _auto_interpret() if interpret is None else interpret
+    # Each expert step holds the systolic kernel's working set: size the
+    # compiler's VMEM limit by the same plan accounting.
+    vmem_limit = BlockPlan(
+        c, n, k, bc, bn, bk, in_dtype=str(x.dtype), out_dtype_bytes=out_dtype.itemsize
+    ).vmem_limit_bytes(chip)
     return _grouped_jit(
-        x, w, out_dtype=str(out_dtype), bc=bc, bn=bn, bk=bk, interpret=interpret
+        x, w, out_dtype=str(out_dtype), bc=bc, bn=bn, bk=bk, interpret=interpret,
+        vmem_limit=vmem_limit,
     )

@@ -85,6 +85,7 @@ def systolic_matmul_call(
     out_dtype,
     activation: str = "none",
     interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """Raw pallas_call wrapper; shapes must already divide the blocks.
 
@@ -115,7 +116,9 @@ def systolic_matmul_call(
         ),
         transcendentals=0,
     )
-    params = tpu_compiler_params(("parallel", "parallel", "arbitrary"))
+    params = tpu_compiler_params(
+        ("parallel", "parallel", "arbitrary"), vmem_limit_bytes
+    )
 
     if bias is None:
         kernel = functools.partial(_mmm_kernel, n_k=grid[2], activation=activation)
@@ -209,6 +212,7 @@ def quant_systolic_matmul_call(
     out_dtype,
     activation: str = "none",
     interpret: bool = False,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """Raw quantized pallas_call; shapes must already divide the blocks.
 
@@ -238,9 +242,19 @@ def quant_systolic_matmul_call(
     a_spec = pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk))
     b_spec = pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))
     # Scale blocks advance once per *quant* block, not per k-step: the index
-    # map lands k-step kk inside scale column (kk * bk) // qk.
-    as_spec = pl.BlockSpec((bm, 1), lambda i, j, kk: (i, (kk * bk) // qk_a))
-    bs_spec = pl.BlockSpec((1, bn), lambda i, j, kk: ((kk * bk) // qk_b, j))
+    # map lands k-step kk inside scale block (kk * bk) // qk.  The scale
+    # block index leads a 3-D layout and is squeezed out of the window, so
+    # the kernel's (bm, 1) / (1, bn) windows span their array's whole unit
+    # dimension -- the only way a width-1 block meets Mosaic's (8, 128)
+    # tiling rule.
+    a_s3 = a_scales.T.reshape(a_scales.shape[1], m, 1)
+    b_s3 = b_scales.reshape(b_scales.shape[0], 1, n)
+    as_spec = pl.BlockSpec(
+        (None, bm, 1), lambda i, j, kk: ((kk * bk) // qk_a, i, 0)
+    )
+    bs_spec = pl.BlockSpec(
+        (None, 1, bn), lambda i, j, kk: ((kk * bk) // qk_b, 0, j)
+    )
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
 
     cost = pl.CostEstimate(
@@ -262,9 +276,9 @@ def quant_systolic_matmul_call(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")
+            ("parallel", "parallel", "arbitrary"), vmem_limit_bytes
         ),
         cost_estimate=cost,
         interpret=interpret,
         name=f"systolic_qmm_{a.dtype.name}_{bm}x{bn}x{bk}_{activation}",
-    )(a, a_scales, b, b_scales)
+    )(a, a_s3, b, b_s3)

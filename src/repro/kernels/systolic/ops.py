@@ -52,6 +52,20 @@ def _clamp_plan(
     return bm, bn, bk
 
 
+def _vmem_limit(
+    m: int, n: int, k: int, blocks, in_dtype, out_dtype, chip, *, quant=False
+) -> int:
+    """The scoped-VMEM limit for running blocks (bm, bn, bk): the same
+    ``BlockPlan`` accounting the fitter admitted the plan with."""
+    bm, bn, bk = blocks
+    return BlockPlan(
+        m, n, k, bm, bn, bk,
+        in_dtype=str(in_dtype),
+        quant_block_k=bk if quant else 0,
+        out_dtype_bytes=jnp.dtype(out_dtype).itemsize,
+    ).vmem_limit_bytes(chip)
+
+
 def _tuned_block(
     m: int, n: int, k: int, dtype, activation: str, chip: hw.Chip
 ) -> tuple[int, int, int] | None:
@@ -70,9 +84,13 @@ def _tuned_block(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("out_dtype", "activation", "bm", "bn", "bk", "interpret"),
+    static_argnames=(
+        "out_dtype", "activation", "bm", "bn", "bk", "interpret", "vmem_limit"
+    ),
 )
-def _matmul_jit(a, b, bias, *, out_dtype, activation, bm, bn, bk, interpret):
+def _matmul_jit(
+    a, b, bias, *, out_dtype, activation, bm, bn, bk, interpret, vmem_limit
+):
     m, k = a.shape
     n = b.shape[1]
     mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(k, bk)
@@ -91,6 +109,7 @@ def _matmul_jit(a, b, bias, *, out_dtype, activation, bm, bn, bk, interpret):
         out_dtype=out_dtype,
         activation=activation,
         interpret=interpret,
+        vmem_limit_bytes=vmem_limit,
     )
     return y[:m, :n]
 
@@ -147,6 +166,7 @@ def matmul(
         bn=bn,
         bk=bk,
         interpret=interpret,
+        vmem_limit=_vmem_limit(m, n, k, (bm, bn, bk), a.dtype, out_dtype, chip),
     )
 
 
@@ -186,10 +206,12 @@ def _col_scales(q: QArray, k: int, n: int) -> tuple[jax.Array, int]:
         "qk_a",
         "qk_b",
         "interpret",
+        "vmem_limit",
     ),
 )
 def _quant_matmul_jit(
-    av, a_s, bv, b_s, *, out_dtype, activation, bm, bn, bk, qk_a, qk_b, interpret
+    av, a_s, bv, b_s, *, out_dtype, activation, bm, bn, bk, qk_a, qk_b,
+    interpret, vmem_limit,
 ):
     m, k = av.shape
     n = bv.shape[1]
@@ -223,6 +245,7 @@ def _quant_matmul_jit(
         out_dtype=out_dtype,
         activation=activation,
         interpret=interpret,
+        vmem_limit_bytes=vmem_limit,
     )
     return y[:m, :n]
 
@@ -307,4 +330,7 @@ def quant_matmul(
         qk_a=qk_a,
         qk_b=qk_b,
         interpret=interpret,
+        vmem_limit=_vmem_limit(
+            m, n, k, (bm, bn, bk), dtype_name, out_dtype, chip, quant=True
+        ),
     )

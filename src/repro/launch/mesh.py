@@ -19,10 +19,23 @@ from __future__ import annotations
 import jax
 
 
+def make_mesh(shape, axes, *, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis in ``Auto`` mode.
+
+    JAX 0.9 defaults new meshes to ``Explicit`` axes, under which the
+    GSPMD-style code here (``param_shardings`` + ``with mesh`` +
+    ``annotate``) is refused, e.g. a gather from a model-sharded embedding
+    table.  Every mesh of the repo is built here so all of it runs in the
+    mode it was written for.
+    """
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto, devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
@@ -37,7 +50,7 @@ def make_local_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
             f"XLA_FLAGS=--xla_force_host_platform_device_count={data * model} "
             f"python -m repro.launch.serve --model-parallel {model} ...)."
         )
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def batch_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

@@ -67,6 +67,7 @@ from repro.data.synthetic import (
     make_batch,
     make_request_trace,
 )
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.registry import get_model
 from repro.serving import (
     ContinuousScheduler,
@@ -510,6 +511,7 @@ def main() -> None:
         "0 disables; default $REPRO_PROFILE_RATE or 0",
     )
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.profile_sample_rate is not None:
         from repro.obs import profile as _obs_profile

@@ -20,6 +20,7 @@ from repro import configs
 from repro.data.sharded import TokenShardDataset, write_synthetic_shards
 from repro.data.synthetic import make_batch
 from repro.distributed import annotate, sharding
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models.registry import get_model
 from repro.train.loop import TrainConfig, Trainer
@@ -44,6 +45,7 @@ def main() -> None:
     ap.add_argument("--distributed", action="store_true", help="multi-host init")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     if args.distributed:
         jax.distributed.initialize()

@@ -96,8 +96,8 @@ def probe_decode_plans(
     Uses the cached plan's block geometry when the cache has an entry for
     the problem (apples-to-apples with its stored ``mean_us``) and the
     analytical heuristic's blocks otherwise.  Returns a summary row per
-    problem; failures to measure one problem are recorded and skipped, so
-    a probe never takes the serve process down.
+    problem.  A measurement that fails raises: a probe that skipped the
+    problem would report a clean run that measured nothing.
     """
     from repro.core import hw
     from repro.core.blocking import derive_block_plan
@@ -116,15 +116,11 @@ def probe_decode_plans(
                 bm, bn, bk = bp.bm, bp.bn, bp.bk
             except (ValueError, ZeroDivisionError):
                 continue
-        try:
-            ms = tune_measure.measure_matmul(
-                m, n, k, bm, bn, bk,
-                dtype=dtype, backend="pallas-systolic",
-                method=method, repeats=repeats, warmup=warmup,
-            )
-        except Exception as e:  # pragma: no cover - defensive probe
-            rows.append({"name": name, "problem": f"{m}x{n}x{k}", "error": str(e)})
-            continue
+        ms = tune_measure.measure_matmul(
+            m, n, k, bm, bn, bk,
+            dtype=dtype, backend="pallas-systolic",
+            method=method, repeats=repeats, warmup=warmup,
+        )
         _profile.record_gemm_sample(
             m, n, k,
             backend="pallas-systolic", dtype=dtype,

@@ -103,8 +103,9 @@ def main(argv: list[str] | None = None) -> int:
     if key.chip != hw.get_chip(None).name:
         # Dispatch looks plans up under the process-default chip; a plan
         # tuned for another target is invisible until the default matches.
-        print(f"note   dispatch serves chip={hw.get_chip(None).name!r} by "
-              f"default; set REPRO_CHIP={key.chip} to serve this plan")
+        print(f"note   dispatch serves chip={hw.get_chip(None).name!r} here; "
+              f"this plan serves on a {key.chip} TPU (or REPRO_CHIP={key.chip} "
+              f"off TPU)")
     return 0
 
 

@@ -10,13 +10,14 @@ import numpy as np
 from repro.configs import get_smoke
 from repro.data.synthetic import make_batch
 from repro.distributed import annotate, sharding
+from repro.launch.mesh import make_mesh
 from repro.models.registry import get_model
 from repro.optim import adamw_init
 from repro.train.loop import TrainConfig, make_train_step
 
 
 def _mesh():
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return make_mesh((4, 2), ("data", "model"))
 
 
 def train_equiv():
@@ -107,7 +108,7 @@ def moe_ep():
 
 
 def _tp_mesh():
-    return jax.make_mesh((8,), ("model",))
+    return make_mesh((8,), ("model",))
 
 
 def tp_allgather():
@@ -212,7 +213,7 @@ def tp_serve_equiv():
     scfg = ServeConfig(max_len=24, batch=2)
 
     ref = ServeEngine(model, params, scfg).generate(batch, 8)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     got = ServeEngine(model, params, scfg, mesh=mesh).generate(batch, 8)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
 
@@ -221,7 +222,7 @@ def tp_serve_equiv():
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         ServeEngine(
-            model, params, scfg, mesh=jax.make_mesh((1, 8), ("data", "model"))
+            model, params, scfg, mesh=make_mesh((1, 8), ("data", "model"))
         )
     assert any("n_heads" in str(w.message) for w in caught), [
         str(w.message) for w in caught

@@ -13,6 +13,7 @@ from repro.checkpoint import (
     restore_checkpoint,
     save_checkpoint,
 )
+from repro.launch.mesh import make_mesh
 
 
 def _tree(seed=0):
@@ -70,7 +71,7 @@ def test_elastic_reshard_restore(tmp_path):
     the elastic path a downsized restart takes."""
     t = _tree()
     save_checkpoint(str(tmp_path), 5, t)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), t)

@@ -14,6 +14,7 @@ import pytest
 
 from repro.configs import ALL_ARCHS, get_smoke
 from repro.distributed import sharding
+from repro.launch.mesh import make_mesh
 from repro.models.registry import get_model
 
 _HELPER = os.path.join(os.path.dirname(__file__), "_distributed_helper.py")
@@ -22,7 +23,7 @@ _HELPER = os.path.join(os.path.dirname(__file__), "_distributed_helper.py")
 def test_param_specs_cover_every_leaf():
     """Every arch's every param leaf gets a spec with matching rank and
     divisible shardings (rule completeness)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for arch in ALL_ARCHS:
         cfg = get_smoke(arch)
         model = get_model(cfg)
@@ -37,7 +38,7 @@ def test_param_specs_cover_every_leaf():
 
 
 def test_cache_specs_cover_every_leaf():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for arch in ALL_ARCHS:
         cfg = get_smoke(arch)
         model = get_model(cfg)
@@ -49,7 +50,7 @@ def test_cache_specs_cover_every_leaf():
 
 
 def test_zero1_adds_data_axis():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = {"w_gate": jax.ShapeDtypeStruct((64, 128), jnp.float32)}
     z = sharding.zero1_specs(params, mesh)
     # data axis size 1 -> divisible, placed on the first free dim
@@ -160,7 +161,7 @@ def test_tp_tuned_block_clamps_to_shard_problem(tmp_path, monkeypatch):
 def test_tensor_parallel_context_rejects_missing_axis():
     from repro.distributed import collective_matmul as cm
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="no axis"):
         with cm.tensor_parallel(mesh, axis="pod"):
             pass

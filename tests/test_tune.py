@@ -384,3 +384,43 @@ def test_chip_registry():
     finally:
         hw.set_default_chip("tpu_v5e")
     assert hw.get_chip(None) is hw.TPU_V5E
+
+
+class _FakeDevice:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+@pytest.mark.parametrize("kind,name", [("TPU v5 lite", "tpu_v5e"), ("TPU v4", "tpu_v4")])
+def test_attached_tpu_resolves_from_device_kind(monkeypatch, kind, name):
+    """On a TPU backend the default chip follows ``device_kind``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeDevice(kind)])
+    monkeypatch.setattr(hw, "_override", None)
+    monkeypatch.setenv("REPRO_CHIP", "tpu_v4" if name == "tpu_v5e" else "tpu_v5e")
+    hw.attached_chip_name.cache_clear()
+    try:
+        assert hw.get_chip(None).name == name
+    finally:
+        hw.attached_chip_name.cache_clear()
+
+
+def test_unknown_tpu_kind_raises(monkeypatch):
+    """An attached TPU with no chip entry is an error, never a v5e default."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeDevice("TPU v99")])
+    monkeypatch.setattr(hw, "_override", None)
+    hw.attached_chip_name.cache_clear()
+    try:
+        with pytest.raises(KeyError, match="TPU v99"):
+            hw.get_chip(None)
+    finally:
+        hw.attached_chip_name.cache_clear()
+
+
+def test_unknown_repro_chip_raises(monkeypatch):
+    """Off TPU, REPRO_CHIP names the modelled chip; a typo is an error."""
+    monkeypatch.setattr(hw, "_override", None)
+    monkeypatch.setenv("REPRO_CHIP", "tpu_v5f")
+    with pytest.raises(KeyError, match="tpu_v5f"):
+        hw.get_chip(None)
