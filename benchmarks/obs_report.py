@@ -11,7 +11,7 @@ on the jitted step itself (dispatch records at trace time), and one
 block_until_ready window per sampled pool dispatch on the profiled path.
 
 The enabled arm doubles as the utilisation-accounting smoke: its BENCH JSON
-carries the decode MFU, roofline model residual, tune-plan hit rate,
+carries the roofline model residual, tune-plan hit rate,
 TTFT/ITL percentiles, and resident KV bytes of the run, plus structural
 validation of the metrics snapshot and the exported Chrome trace.
 
@@ -126,7 +126,6 @@ def run(
         "overhead_budget": max_overhead,
         "overhead_ok": overhead < max_overhead,
         "profile_sample_rate": PROFILE_RATE,
-        "decode_mfu": s["decode_mfu"],
         "model_residual": s["model_residual"],
         "plan_hit_rate": round(obs.plan_hit_rate("pallas-systolic"), 4),
         "ttft_p50_ms": s["ttft_p50_ms"],

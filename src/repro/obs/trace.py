@@ -1,21 +1,37 @@
 """Span tracer: wall-time events in a ring buffer, exported as Chrome
-``trace_event`` JSON (loadable in Perfetto / chrome://tracing).
+``trace_event`` JSON (loadable in Perfetto / chrome://tracing), and the same
+spans on the JAX profiler's host plane.
 
 The paper reasons about utilisation with pipeline timelines (Section V's
 overlapped Read/Compute/Write phases); this is the host-side equivalent for
-the serving stack: every scheduler tick, prefill chunk, decode step, autotune
-measurement, and collective dispatch opens a span, and the exported timeline
-shows where wall time actually went -- the overlap (or bubble) is visible
-instead of inferred.
+the serving stack: every scheduler tick and each of its phases, prefill
+chunk, decode step, autotune measurement, and collective dispatch opens a
+span, and the exported timeline shows where wall time actually went -- the
+overlap (or bubble) is visible instead of inferred.
 
-Scope of honesty: spans time **host-side dispatch**, not device execution.
-A span around a jitted call covers trace+compile on its first invocation and
-the blocking wait on subsequent ones (serving code calls
-``block_until_ready`` inside its spans, so steady-state spans do bound the
-device step).  Events recorded while a jax trace is being staged (e.g. the
-per-hop spans of the collective matmul) are *trace-time* events: near-zero
-duration, tagged ``cat="trace"``, carrying their payload (bytes, shapes) in
-``args`` -- structural markers, not timings.
+One tracer, two sinks.  Every span and instant is recorded in the ring
+buffer (what ``export_chrome``, ``--metrics-dir``'s ``trace.json`` and the
+flight recorder read) and, while a JAX profiler trace is recording, as a
+``jax.profiler.TraceAnnotation`` of the same name with the same args.  The
+annotation lands on the profiler's ``/host`` plane, on the profile's clock
+beside the device's ``XLA Ops`` and ``XLA Modules`` lines, so a profile
+puts each device op and each idle gap beside the host phase that was
+running (on a v5e the device's timestamps run about a millisecond ahead of
+the host's; ``bench/lib/program_metrics.py`` aligns the two).  With no
+trace recording the annotation is not opened and no arg is formatted for
+it.  ``span`` yields a handle whose ``set(**args)`` adds args before the
+span ends (counts known only at its end), to both sinks.
+
+Scope of honesty: the ring buffer times **host-side dispatch** on
+``perf_counter``, not device execution.  A span around a jitted call covers
+trace+compile on its first invocation and the host's dispatch on later
+ones; a span around ``block_until_ready`` covers the wait for the device
+(the scheduler's ``serve.prefill_wait`` / ``serve.decode_wait``).  What the
+device did inside a span is read from a profiler trace, where the span
+sits beside the device's ops.  Events recorded while a jax trace is being
+staged (e.g. the per-hop spans of the collective matmul) are *trace-time*
+events: near-zero duration, tagged ``cat="trace"``, carrying their payload
+(bytes, shapes) in ``args`` -- structural markers, not timings.
 
 The buffer is a bounded deque: a long-running server keeps the most recent
 ``capacity`` events and drops the oldest -- export never grows without
@@ -42,6 +58,8 @@ import threading
 import time
 from collections import deque
 from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import metrics as _metrics
 
@@ -73,6 +91,65 @@ def request_scope(rid: int):
         _REQUEST.reset(token)
 
 
+def _payload(args: dict, drop_none: bool = True) -> dict:
+    """An event's args: the given ones (spans drop None values), tagged
+    with the enclosing ``request_scope``'s rid unless a rid/rids is given."""
+    payload = dict(args)
+    if drop_none:
+        payload = {k: v for k, v in payload.items() if v is not None}
+    if "rid" not in payload and "rids" not in payload:
+        rid = _REQUEST.get()
+        if rid is not None:
+            payload["rid"] = rid
+    return payload
+
+
+def _annotation_args(args: dict) -> dict:
+    """Args as the profiler stores them: numbers as they are, the rest as
+    text (an annotation's stats are scalars)."""
+    return {
+        k: v if isinstance(v, (bool, int, float, str)) else str(v)
+        for k, v in args.items()
+    }
+
+
+class Span:
+    """An open span: its args, and the profiler annotation that mirrors it
+    while a trace records."""
+
+    __slots__ = ("args", "_ann")
+
+    def __init__(self, name: str, args: dict):
+        self.args = args
+        self._ann = None
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(name, **_annotation_args(args))
+            self._ann.__enter__()
+
+    def set(self, **args) -> None:
+        """Add (or overwrite) args; both sinks see them at the span's end."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**_annotation_args(args))
+
+    def close(self) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+
+class _NoSpan:
+    """What ``span`` yields while recording is off."""
+
+    __slots__ = ()
+
+    def set(self, **args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
 class Tracer:
     """Ring buffer of completed spans + instants."""
 
@@ -84,6 +161,12 @@ class Tracer:
         self._lock = threading.Lock()
         self._t0 = time.perf_counter()
         self._dropped = 0
+
+    @property
+    def t0(self) -> float:
+        """The ``time.perf_counter()`` reading that an event's ``ts`` of 0
+        stands for (``ts`` is microseconds after it)."""
+        return self._t0
 
     # -- recording -----------------------------------------------------------
 
@@ -98,22 +181,25 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "", **args):
-        """Record a complete ("ph": "X") event around the enclosed block.
+        """Record a complete ("ph": "X") event around the enclosed block,
+        and a profiler annotation while a trace records.
 
+        Yields a ``Span`` whose ``set(**args)`` adds args before the end.
         The span is recorded even if the block raises (with an ``error``
         arg), so a crashed tick still shows up in the timeline.
         """
         if not _metrics.enabled():
-            yield
+            yield _NO_SPAN
             return
+        handle = Span(name, _payload(args))
         start = self._now_us()
-        err = None
         try:
-            yield
+            yield handle
         except BaseException as e:
-            err = type(e).__name__
+            handle.set(error=type(e).__name__)
             raise
         finally:
+            handle.close()
             ev = {
                 "name": name,
                 "cat": cat or "span",
@@ -123,19 +209,13 @@ class Tracer:
                 "pid": os.getpid(),
                 "tid": threading.get_ident() & 0xFFFF,
             }
-            payload = {k: v for k, v in args.items() if v is not None}
-            if err is not None:
-                payload["error"] = err
-            if "rid" not in payload and "rids" not in payload:
-                rid = _REQUEST.get()
-                if rid is not None:
-                    payload["rid"] = rid
-            if payload:
-                ev["args"] = payload
+            if handle.args:
+                ev["args"] = handle.args
             self._push(ev)
 
     def instant(self, name: str, cat: str = "", **args) -> None:
-        """Record a zero-duration ("ph": "i") marker."""
+        """Record a zero-duration ("ph": "i") marker (and a zero-length
+        profiler annotation while a trace records)."""
         if not _metrics.enabled():
             return
         ev = {
@@ -147,13 +227,12 @@ class Tracer:
             "pid": os.getpid(),
             "tid": threading.get_ident() & 0xFFFF,
         }
-        payload = dict(args)
-        if "rid" not in payload and "rids" not in payload:
-            rid = _REQUEST.get()
-            if rid is not None:
-                payload["rid"] = rid
+        payload = _payload(args, drop_none=False)
         if payload:
             ev["args"] = payload
+        if TraceAnnotation.is_enabled():
+            with TraceAnnotation(name, **_annotation_args(payload)):
+                pass
         self._push(ev)
 
     def instrument(self, name: str | None = None, cat: str = ""):

@@ -181,19 +181,24 @@ class ServeEngine:
             p_sh = dist_sharding.param_shardings(params, mesh)
             params = jax.device_put(params, p_sh)
         self.params = params
-        self._prefill = jax.jit(
-            lambda p, b: model.prefill(p, b, max_len=scfg.max_len)
-        )
-        self._decode = jax.jit(
-            lambda p, t, c, pos: model.decode_step(p, t, cache=c, pos=pos),
-            donate_argnums=(2,),
-        )
-        self._chunk = jax.jit(
-            lambda p, t, c, off, wrapped: model.prefill_chunk(
+
+        # Named, so that a profiler trace's ``XLA Modules`` line tells the
+        # steps apart: jit_prefill, jit_decode_step, jit_prefill_chunk.
+        def prefill(p, b):
+            return model.prefill(p, b, max_len=scfg.max_len)
+
+        def decode_step(p, t, c, pos):
+            return model.decode_step(p, t, cache=c, pos=pos)
+
+        def prefill_chunk(p, t, c, off, wrapped):
+            return model.prefill_chunk(
                 p, {"tokens": t}, cache=c, offset=off, wrapped=wrapped
-            ),
-            static_argnums=(4,),
-            donate_argnums=(2,),
+            )
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode_step, donate_argnums=(2,))
+        self._chunk = jax.jit(
+            prefill_chunk, static_argnums=(4,), donate_argnums=(2,)
         )
         self._key = jax.random.PRNGKey(scfg.seed)
         self.cache = None

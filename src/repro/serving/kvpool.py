@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import profile as _obs_profile
+from repro.obs import trace as _obs_trace
 
 # ---------------------------------------------------------------------------
 # Quantized pool storage (kv8, DESIGN.md §10).
@@ -337,11 +338,12 @@ class KVPool:
         chunk advances before ``write_slot`` puts it back."""
         if not 0 <= slot < self.n_slots:
             raise ValueError(f"gather of invalid slot {slot}")
-        return _obs_profile.sample_call(
-            "kv.gather",
-            lambda: _gather_slot(self.cache, jnp.int32(slot)),
-            pool="stripe", path="slot",
-        )
+        with _obs_trace.span("kv.gather_slot", cat="kv", slot=slot):
+            return _obs_profile.sample_call(
+                "kv.gather",
+                lambda: _gather_slot(self.cache, jnp.int32(slot)),
+                pool="stripe", path="slot",
+            )
 
     def write_slot(self, slot: int, cache_one: Any, next_pos: int | None) -> None:
         """Scatter a batch-1 cache back into ``slot``.
@@ -363,9 +365,10 @@ class KVPool:
             self.cache = _scatter_slot(self.cache, cache_one, jnp.int32(slot))
             return self._qcache if self.quantize_kv else self._cache
 
-        _obs_profile.sample_call(
-            "kv.scatter", _scatter, pool="stripe", path="slot"
-        )
+        with _obs_trace.span("kv.write_slot", cat="kv", slot=slot):
+            _obs_profile.sample_call(
+                "kv.scatter", _scatter, pool="stripe", path="slot"
+            )
         if next_pos is not None:
             self.positions[slot] = next_pos
 

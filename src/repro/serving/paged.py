@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import profile as _obs_profile
+from repro.obs import trace as _obs_trace
 from repro.serving.kvpool import check_next_pos, dequantize_kv, quantize_kv
 
 
@@ -521,13 +522,14 @@ class PagedKVPool:
         included -- this is what a suffix prefill chunk attends to)."""
         if not 0 <= slot < self.n_slots:
             raise ValueError(f"gather of invalid slot {slot}")
-        return _obs_profile.sample_call(
-            "kv.gather",
-            lambda: _gather_pages(
-                self.phys, self._idx(np.asarray([slot])), self.seq_len
-            ),
-            pool="paged", path="slot",
-        )
+        with _obs_trace.span("kv.gather_slot", cat="kv", slot=slot):
+            return _obs_profile.sample_call(
+                "kv.gather",
+                lambda: _gather_pages(
+                    self.phys, self._idx(np.asarray([slot])), self.seq_len
+                ),
+                pool="paged", path="slot",
+            )
 
     def write_slot(self, slot: int, cache_one: Any, next_pos: int | None) -> None:
         """Scatter a batch-1 cache into ``slot``'s mapped pages.  The
@@ -545,9 +547,10 @@ class PagedKVPool:
             )
             return self._qphys if self.quantize_kv else self._fphys
 
-        _obs_profile.sample_call(
-            "kv.scatter", _scatter, pool="paged", path="slot"
-        )
+        with _obs_trace.span("kv.write_slot", cat="kv", slot=slot):
+            _obs_profile.sample_call(
+                "kv.scatter", _scatter, pool="paged", path="slot"
+            )
         if next_pos is not None:
             self.positions[slot] = next_pos
 

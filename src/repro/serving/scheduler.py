@@ -51,7 +51,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs import attribution as _obs_attr
 from repro.obs import metrics as _obs_metrics
 from repro.obs import slo as _obs_slo
 from repro.obs import trace as _obs_trace
@@ -130,7 +129,7 @@ class SchedulerStats:
     The raw instruments are used directly (not the registry's gated
     convenience wrappers): scheduling correctness bookkeeping -- token
     counts, occupancy, latencies -- must not vanish under ``REPRO_OBS=0``;
-    only the derived-telemetry extras (MFU, residual, spans) are gated.
+    only the derived-telemetry extras (model residual, spans) are gated.
 
     Percentiles come from ``obs.metrics.Histogram.quantile`` -- nearest-rank,
     clamped, so p99 over fewer than 100 samples reports the max instead of
@@ -165,7 +164,6 @@ class SchedulerStats:
         self._queue_wait = r.histogram("serve.queue_wait_s")
         self._goodput = r.counter("serve.goodput_toks")
         self._conformant = r.counter("serve.requests_conformant")
-        self._mfu = r.histogram("serve.decode_mfu")
         self._residual = r.histogram("serve.model_residual")
         self._queue_depth = r.gauge("sched.queue_depth")
         self._slot_occupancy = r.gauge("sched.slot_occupancy")
@@ -240,8 +238,7 @@ class SchedulerStats:
     def record_tick_latency(self, wall_s: float) -> None:
         self._tick_lat.observe(wall_s)
 
-    def record_utilization(self, mfu: float, residual: float) -> None:
-        self._mfu.observe(mfu)
+    def record_residual(self, residual: float) -> None:
         self._residual.observe(residual)
 
     def set_gauges(
@@ -350,7 +347,6 @@ class SchedulerStats:
             "ttft_p99_ms": round(self._ttft.quantile(0.99) * 1e3, 3),
             "itl_p50_ms": round(self._itl.quantile(0.5) * 1e3, 3),
             "itl_p99_ms": round(self._itl.quantile(0.99) * 1e3, 3),
-            "decode_mfu": round(self._mfu.mean(), 6),
             "model_residual": round(self._residual.mean(), 4),
             "kv_bytes_resident": int(self._kv_bytes.value),
             "kv_bytes_live": int(self._kv_bytes_live.value),
@@ -395,7 +391,11 @@ class ContinuousScheduler:
         prefix_cache: bool = False,
         slo=None,
         flight_recorder=None,
+        clock=time.perf_counter,
     ):
+        """``clock`` is the host clock every latency, tick and run-wall
+        reading comes from (seconds, monotonic); tests inject a
+        deterministic one."""
         if policy not in self.POLICIES:
             raise ValueError(f"policy must be one of {self.POLICIES}, got {policy!r}")
         if chunk_size < 1:
@@ -489,7 +489,8 @@ class ContinuousScheduler:
         self.queue: collections.deque[Request] = collections.deque()
         self.tick = 0
         self.stats = SchedulerStats()
-        self._t0 = time.perf_counter()
+        self.clock = clock
+        self._t0 = clock()
         self._gang_forming = False
         self._warmed = False
         # Set when a request is preempted under page pressure; blocks
@@ -585,7 +586,7 @@ class ContinuousScheduler:
         consecutive tokens.
         """
         req.out.append(tok)
-        now = time.perf_counter() - self._t0
+        now = self.clock() - self._t0
         ttft = itl = None
         if req.first_token_s < 0:
             req.first_token_s = now
@@ -705,23 +706,26 @@ class ContinuousScheduler:
             return self.pool.n_active == 0 or self._gang_forming
         return True
 
-    def _admit(self) -> None:
+    def _admit(self) -> list[int]:
+        """Admit arrived requests into free slots; returns their rids."""
         # Queue-wait starts when the arrival tick is *reached* (the request
         # became eligible for a slot), not when it was submitted -- waiting
         # for your own arrival time is not the scheduler's fault.
-        now = time.perf_counter() - self._t0
+        now = self.clock() - self._t0
         for r in self.queue:
             if r.eligible_s < 0 and r.arrival <= self.tick:
                 r.eligible_s = now
         self._gang_forming = self.policy == "gang" and self.pool.n_active == 0
+        admitted = []
         while self._admissible():
             req = self.queue.popleft()
+            admitted.append(req.rid)
             slot = self.pool.alloc()
             assert slot is not None
             req.state = PREFILLING
             req.slot = slot
             req.admitted_tick = self.tick
-            req.admitted_s = time.perf_counter() - self._t0
+            req.admitted_s = self.clock() - self._t0
             wait = (
                 max(0.0, req.admitted_s - req.eligible_s)
                 if req.eligible_s >= 0
@@ -780,7 +784,7 @@ class ContinuousScheduler:
                 req.chunk_idx = 0
                 self._prefilling.append(req)
                 continue
-            t0 = time.perf_counter()
+            t0 = self.clock()
             with _obs_trace.request_scope(req.rid), _obs_trace.span(
                 "serve.prefill",
                 rid=req.rid,
@@ -802,9 +806,10 @@ class ContinuousScheduler:
                     self.pool.write_slot(slot, cache_one, next_pos=n_pos)
                 else:
                     self.pool.write_prefill(slot, cache_one, n_pos)
-            self.stats.add_prefill(time.perf_counter() - t0)
+            self.stats.add_prefill(self.clock() - t0)
             tok = np.asarray(first)[0]  # (1,) or (1, ncb)
             self._start_decoding(req, tok)
+        return admitted
 
     def _start_decoding(self, req: Request, tok: np.ndarray) -> None:
         """Prefill complete: seed the slot's token and flip to DECODING."""
@@ -835,7 +840,7 @@ class ContinuousScheduler:
             req = self._prefilling[0]
             off, length = req.chunks[req.chunk_idx]
             last = req.chunk_idx == len(req.chunks) - 1
-            t0 = time.perf_counter()
+            t0 = self.clock()
             with _obs_trace.request_scope(req.rid), _obs_trace.span(
                 "serve.prefill_chunk",
                 rid=req.rid, offset=off, length=length, last=last,
@@ -856,9 +861,10 @@ class ContinuousScheduler:
                 tok, cache_one = self.engine.prefill_chunk(
                     tokens, cache_one, off, last=last
                 )
-                jax.block_until_ready(
-                    tok if last else jax.tree.leaves(cache_one)[0]
-                )
+                with _obs_trace.span("serve.prefill_wait", rid=req.rid):
+                    jax.block_until_ready(
+                        tok if last else jax.tree.leaves(cache_one)[0]
+                    )
                 if staged and not last:
                     req.staging = cache_one
                 else:
@@ -882,18 +888,18 @@ class ContinuousScheduler:
                             self._prepare_pages(req.slot, off, end)
                     self.pool.write_slot(req.slot, cache_one, next_pos)
                     req.staging = None if last else cache_one
-            self.stats.add_prefill(time.perf_counter() - t0, chunk=True)
-            req.chunk_idx += 1
-            budget -= 1
-            if last:
-                req.staging = None
-                self._prefilling.popleft()
-                self._start_decoding(req, np.asarray(tok)[0])
+                self.stats.add_prefill(self.clock() - t0, chunk=True)
+                req.chunk_idx += 1
+                budget -= 1
+                if last:
+                    req.staging = None
+                    self._prefilling.popleft()
+                    self._start_decoding(req, np.asarray(tok)[0])
 
-    def _decode_once(self) -> bool:
-        """One vector-pos decode step; False when no slot was decoding
-        (idle accounting lives in ``step``, which knows whether the tick
-        did prefill-chunk work instead)."""
+    def _decode_once(self) -> int:
+        """One vector-pos decode step; returns the number of slots decoded
+        (0: none was decoding -- idle accounting lives in ``step``, which
+        knows whether the tick did prefill-chunk work instead)."""
         if self.paged:
             # Map (and COW, for SWA wraps into shared pages) the one row
             # each decoding slot writes this step.  Preparing can itself
@@ -907,39 +913,45 @@ class ContinuousScheduler:
                 self._prepare_pages(slot, idx, idx + 1)
         active = sorted(self._slot_req)
         if not active:
-            return False
-        t0 = time.perf_counter()
+            return 0
+        rids = [self._slot_req[s].rid for s in active]
         with _obs_trace.span(
-            "serve.decode_tick",
-            tick=self.tick,
-            active=len(active),
-            rids=[self._slot_req[s].rid for s in active],
+            "serve.decode_tick", tick=self.tick, active=len(active), rids=rids
         ):
+            t0 = self.clock()
+            with _obs_trace.span("serve.decode_inputs", rids=rids):
+                tok = jnp.asarray(self._slot_tok)
+                pos = self.pool.pos_vector()
             nxt, self.pool.cache = self.engine.decode_slots(
-                jnp.asarray(self._slot_tok), self.pool.cache, self.pool.pos_vector()
+                tok, self.pool.cache, pos
             )
-            nxt = jax.block_until_ready(nxt)
-        dt = time.perf_counter() - t0
-        self.stats.record_decode_step(dt, len(active) / self.pool.n_slots)
-        if _obs_metrics.enabled():
-            # Utilization attribution (DESIGN.md §11): divide the measured
-            # step into the FLOPs/roofline totals the engine's traced decode
-            # step recorded at compile time.
-            totals = self.engine.decode_totals
-            if totals.flops > 0 and dt > 0:
-                self.stats.record_utilization(
-                    _obs_attr.mfu(totals.flops, dt, dtype=self.engine.cfg.dtype),
-                    dt / totals.predicted_s if totals.predicted_s > 0 else 0.0,
-                )
-        nxt_np = np.asarray(nxt)
-        self.pool.advance(active)
-        for slot in active:
-            req = self._slot_req[slot]
-            tok = nxt_np[slot]  # (1,) or (1, ncb)
-            self._slot_tok[slot] = tok
-            if self._token_done(req, tok[0]):
-                self._finish(req)
-        return True
+            with _obs_trace.span("serve.decode_wait", rids=rids):
+                nxt = jax.block_until_ready(nxt)
+            dt = self.clock() - t0
+            # The step's results to the host: stats, positions, one token
+            # per active slot, and the evictions of finished requests.
+            with _obs_trace.span("serve.tokens", rids=rids):
+                self.stats.record_decode_step(dt, len(active) / self.pool.n_slots)
+                if _obs_metrics.enabled():
+                    # Model residual (DESIGN.md §11): the measured step
+                    # against the roofline seconds the engine's traced
+                    # decode step recorded at compile time.
+                    totals = self.engine.decode_totals
+                    if totals.flops > 0 and dt > 0:
+                        self.stats.record_residual(
+                            dt / totals.predicted_s
+                            if totals.predicted_s > 0
+                            else 0.0
+                        )
+                nxt_np = np.asarray(nxt)
+                self.pool.advance(active)
+                for slot in active:
+                    req = self._slot_req[slot]
+                    tok = nxt_np[slot]  # (1,) or (1, ncb)
+                    self._slot_tok[slot] = tok
+                    if self._token_done(req, tok[0]):
+                        self._finish(req)
+        return len(active)
 
     # -- driving ---------------------------------------------------------------
 
@@ -971,7 +983,8 @@ class ContinuousScheduler:
             self._warmup_impl()
         self._set_gauges()
 
-    def _set_gauges(self) -> None:
+    def _set_gauges(self) -> dict:
+        """Queue/occupancy/KV gauges; returns the pool's bytes report."""
         # Both pools report {"reserved", "live"}: reserved is allocated-page
         # bytes (paged -- scales with load) or the preallocated stripe
         # (unpaged -- constant); live is written-row bytes under the masks.
@@ -985,6 +998,7 @@ class ContinuousScheduler:
                 self.pool.page_occupancy() if self.paged else None
             ),
         )
+        return rep
 
     def _warmup_impl(self) -> None:
         key_before = self.engine._key  # warmup must not advance sampling
@@ -1042,43 +1056,64 @@ class ContinuousScheduler:
     def step(self) -> bool:
         """One scheduler tick: admit arrived requests, run at most
         ``chunk_budget`` prefill chunks (chunked mode), then one batched
-        decode step over whatever is decoding.  Returns ``pending()``.
+        decode step over whatever is decoding, then the gauges.  Returns
+        ``pending()``.
 
         Ticks in which at least one slot decoded are timed end to end into
         ``stats.tick_latency_s`` -- the latency a decoding request actually
         experiences, prefill work included.
+
+        The tick is one ``serve.tick`` span (args at its end: slots
+        decoded, chunks run, queue and prefilling depth, KV bytes live and
+        reserved) whose children name every phase, so a profiler trace
+        puts each device gap inside the tick under the host work that
+        caused it.
         """
         if not self._warmed:
             # Keep one-off compiles out of every latency histogram even when
             # the driver steps manually and never called warmup() itself.
             self.warmup()
-        t0 = time.perf_counter()
+        t0 = self.clock()
         self._tick_preempted = False
-        try:
-            self._admit()
-            chunks_before = self.stats.prefill_chunks
-            if self.chunked_prefill:
-                self._prefill_chunk_once()
-            decoded = self._decode_once()
-        except Exception as e:
-            # Engine exception: capture the flight recording before the
-            # stack unwinds past the scheduler (the ring buffer still holds
-            # the spans leading up to the failure).
-            if self.flight_recorder is not None:
-                self.flight_recorder.dump(
-                    "exception",
-                    detail={"tick": self.tick, "error": repr(e)},
-                )
-            raise
-        dt = time.perf_counter() - t0
+        rids = [r.rid for r in self._slot_req.values()]
+        rids += [r.rid for r in self._prefilling]
+        with _obs_trace.span("serve.tick", tick=self.tick, rids=rids) as span:
+            try:
+                with _obs_trace.span("serve.admission", rids=[]) as adm:
+                    adm.set(rids=self._admit())
+                chunks_before = self.stats.prefill_chunks
+                if self.chunked_prefill:
+                    self._prefill_chunk_once()
+                decoded = self._decode_once()
+            except Exception as e:
+                # Engine exception: capture the flight recording before the
+                # stack unwinds past the scheduler (the ring buffer still
+                # holds the spans leading up to the failure).
+                if self.flight_recorder is not None:
+                    self.flight_recorder.dump(
+                        "exception",
+                        detail={"tick": self.tick, "error": repr(e)},
+                    )
+                raise
+            chunks = self.stats.prefill_chunks - chunks_before
+            with _obs_trace.span("serve.gauges", rids=rids):
+                kv = self._set_gauges()
+            span.set(
+                decoded=decoded,
+                chunks=chunks,
+                queued=len(self.queue),
+                prefilling=len(self._prefilling),
+                kv_live_bytes=kv["live"],
+                kv_reserved_bytes=kv["reserved"],
+            )
+        dt = self.clock() - t0
         if decoded:
             self.stats.record_tick_latency(dt)
-        elif self.stats.prefill_chunks == chunks_before:
+        elif not chunks:
             # truly idle: no decode ran AND no prefill chunk landed
             self.stats.count_idle_tick()
         self.tick += 1
         self.stats.count_tick(dt)
-        self._set_gauges()
         return self.pending()
 
     def run(
@@ -1101,17 +1136,17 @@ class ContinuousScheduler:
                 self.submit(r)
                 done.append(r)
         self.warmup()
-        self._t0 = time.perf_counter()
+        self._t0 = self.clock()
         limit = max_ticks if max_ticks is not None else 1_000_000
         while self.pending():
             if self.tick >= limit:
                 raise RuntimeError(f"scheduler did not drain in {limit} ticks")
             self.step()
             if on_tick is not None:
-                t_cb = time.perf_counter()
+                t_cb = self.clock()
                 on_tick(self)
-                self.stats.count_callback(time.perf_counter() - t_cb)
+                self.stats.count_callback(self.clock() - t_cb)
         # Measured wall clock of the drained run (warmup excluded): the
         # denominator obs doctor holds tick_s + callback_s against.
-        self.stats.set_run_wall(time.perf_counter() - self._t0)
+        self.stats.set_run_wall(self.clock() - self._t0)
         return {r.rid: r.tokens() for r in done}

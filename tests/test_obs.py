@@ -449,7 +449,7 @@ def test_serve_run_populates_telemetry():
     sched.run(requests_from_trace(trace))
     s = sched.stats.summary()
     assert s["tokens_out"] == sum(t["max_new_tokens"] for t in trace)
-    assert s["decode_mfu"] > 0 and s["model_residual"] > 0
+    assert s["model_residual"] > 0
     assert s["ttft_p50_ms"] > 0 and s["kv_bytes_resident"] > 0
     assert s["itl_p50_ms"] > 0
     # snapshot (dispatch registry + scheduler registry) validates

@@ -304,15 +304,41 @@ def test_probe_decode_plans_records_gemm_samples(cache_path):
         assert labels["method"] in ("interpret-wall", "xla-proxy", "device-wall")
 
 
+class _EngineClock:
+    """A scheduler clock on which time passes only while the engine runs a
+    step, a fixed cost per call: the run's tick/phase/wall accounting then
+    reads the same on an idle machine and on one shared with other test
+    workers, where a stall between two ticks would otherwise land outside
+    every tick."""
+
+    def __init__(self, engine, costs: dict):
+        self.now = 0.0
+        for name, cost in costs.items():
+            setattr(engine, name, self._charged(getattr(engine, name), cost))
+
+    def _charged(self, fn, cost: float):
+        def call(*args, **kwargs):
+            out = fn(*args, **kwargs)
+            self.now += cost
+            return out
+
+        return call
+
+    def __call__(self) -> float:
+        return self.now
+
+
 def test_doctor_end_to_end_serve_report_and_stale_gate(tmp_path, cache_path, capsys):
     """Acceptance: doctor over a real serve run's metrics dir reports a
     measured phase breakdown covering >= 90% of wall, exits 0 when healthy,
     and exits 1 end-to-end when a tune-cache entry is ~3x off the sampled
-    probe timings."""
+    probe timings.  The scheduler runs on ``_EngineClock``, so its
+    accounting (and the coverage) does not depend on the CPU's load."""
     from repro.serving import ContinuousScheduler, requests_from_trace
 
     model, params, engine, trace = _serve_setup()
-    sched = ContinuousScheduler(engine)
+    clock = _EngineClock(engine, {"prefill_request": 5e-3, "decode_slots": 2e-3})
+    sched = ContinuousScheduler(engine, clock=clock)
     with obs.sampling(1.0):
         sched.run(requests_from_trace(trace))
         rows = obs_drift.probe_decode_plans(engine, repeats=1, warmup=1)
