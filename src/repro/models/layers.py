@@ -14,6 +14,8 @@ quantized decode through identical layer code.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -31,6 +33,54 @@ def wcast(w, dtype):
     if isinstance(w, QArray):
         return w
     return w.astype(dtype)
+
+
+# Parameter leaves the layer code only ever reads as ``wcast`` /
+# ``.astype(compute dtype)`` right before a contraction (projections, MLA's
+# ``wkv_b``, MoE experts and router, embedding tables, output heads), named
+# by their dict key.  Everything else -- norm scales, ``conv_w``/``conv_b``,
+# SSM gates and decays, sLSTM's recurrent ``r_h`` -- is computed with in
+# fp32 and is not listed.
+COMPUTE_CAST_LEAVES = frozenset({
+    "wq", "wk", "wv", "wo",  # GQA, mLSTM
+    "wq_a", "wq_b", "wkv_a", "wkv_b",  # MLA
+    "w_gate", "w_up", "w_down", "router",  # SwiGLU, MoE experts
+    "w_if", "w_x", "in_proj", "out_proj",  # mLSTM, sLSTM, Mamba2
+    "w", "w1", "w2",  # dense, lm_head, audio heads, vit projector
+    "table", "tables",  # embeddings
+})
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _astype(w, dtype):
+    return w.astype(dtype)
+
+
+def cast_for_compute(params, dtype):
+    """A copy of ``params`` with every ``COMPUTE_CAST_LEAVES`` leaf in
+    ``dtype``, so a step program's ``wcast``/``astype`` is a no-op.
+
+    The arithmetic is unchanged: the layers cast these leaves to the compute
+    dtype before every use, this only does it once.  Other leaves and
+    ``QArray``s are passed through as they are.  One jitted cast per leaf,
+    so the transient is one leaf's copy; the caller's arrays are neither
+    donated nor modified.
+    """
+    dtype = jnp.dtype(dtype)
+
+    def cast(path, leaf):
+        if (
+            not isinstance(leaf, QArray)
+            and getattr(path[-1], "key", None) in COMPUTE_CAST_LEAVES
+            and jnp.issubdtype(leaf.dtype, jnp.floating)
+            and leaf.dtype != dtype
+        ):
+            return _astype(leaf, dtype)
+        return leaf
+
+    return jax.tree_util.tree_map_with_path(
+        cast, params, is_leaf=lambda x: isinstance(x, QArray)
+    )
 
 
 def _dense_init(key, d_in: int, d_out: int, dtype=jnp.float32) -> jax.Array:

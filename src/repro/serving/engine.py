@@ -33,6 +33,7 @@ on the analytical fallback.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 from typing import Any
@@ -40,6 +41,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.models import layers
 from repro.models.registry import Model
 from repro.obs import attribution as _obs
 from repro.obs import metrics as _obs_metrics
@@ -180,7 +182,15 @@ class ServeEngine:
                 )
             p_sh = dist_sharding.param_shardings(params, mesh)
             params = jax.device_put(params, p_sh)
-        self.params = params
+        # The served copy: weights in the compute dtype, cast once here
+        # rather than in every step program (DESIGN.md §8).  Assigning
+        # ``self.params`` later replaces what the steps read.
+        self.params = layers.cast_for_compute(params, self.cfg.dtype)
+        held = collections.Counter()  # a QArray counts values and scales
+        for leaf in jax.tree.leaves(self.params):
+            held[str(leaf.dtype)] += int(leaf.nbytes)
+        for dt, n in held.items():
+            _obs_metrics.set_gauge("engine.param_bytes", n, dtype=dt)
 
         # Named, so that a profiler trace's ``XLA Modules`` line tells the
         # steps apart: jit_prefill, jit_decode_step, jit_prefill_chunk.

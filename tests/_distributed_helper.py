@@ -217,6 +217,16 @@ def tp_serve_equiv():
     got = ServeEngine(model, params, scfg, mesh=mesh).generate(batch, 8)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
 
+    # At bf16 compute the engine's served copy keeps the TP layout: every
+    # leaf, cast or not, stays on the sharding ``param_shardings`` gave it.
+    eng = ServeEngine(
+        get_model(dataclasses.replace(cfg, dtype="bfloat16")), params, scfg, mesh=mesh
+    )
+    served = jax.tree.leaves(eng.params)
+    assert any(leaf.dtype == jnp.bfloat16 for leaf in served)
+    for want, leaf in zip(jax.tree.leaves(sharding.param_shardings(params, mesh)), served):
+        assert leaf.sharding.is_equivalent_to(want, leaf.ndim), (leaf.shape, leaf.sharding)
+
     import warnings
 
     with warnings.catch_warnings(record=True) as caught:
